@@ -119,12 +119,9 @@ class LoweredPlan:
     def make_mesh(self, shape: Optional[Tuple[int, ...]] = None) -> Mesh:
         names = self.mesh_spec.names
         sizes = shape or tuple(s for _, s in self.mesh_spec.axes)
-        # AxisType landed after jax 0.4.37; older jax means Auto implicitly
-        axis_type = getattr(jax.sharding, "AxisType", None)
-        if axis_type is not None:
-            return jax.make_mesh(
-                sizes, names, axis_types=(axis_type.Auto,) * len(names))
-        return jax.make_mesh(sizes, names)
+        return jax.make_mesh(
+            sizes, names,
+            axis_types=(jax.sharding.AxisType.Auto,) * len(names))
 
     # ---------------------------------------------------------------- shardings
 
@@ -288,14 +285,6 @@ def plan_from_program(prog: ir.Program) -> LoweredPlan:
 # ----------------------------------------------------- explicit sync lowering
 
 
-def axis_size(name: str):
-    """Size of a mapped mesh axis; jax.lax.axis_size on new jax, the psum-of-1
-    identity (folded to a constant at trace time) on <= 0.4.x."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(name)
-    return jax.lax.psum(1, name)
-
-
 def lower_sync(sync: ir.SyncOp, value, axis_env: Optional[Tuple[str, ...]] = None):
     """Lower one SyncOp to its jax.lax collective (explicit/shard_map backend)."""
     axes = tuple(a for a in sync.axes if axis_env is None or a in axis_env)
@@ -326,7 +315,7 @@ def lower_sync(sync: ir.SyncOp, value, axis_env: Optional[Tuple[str, ...]] = Non
         return jax.tree.map(bcast, value)
     if sync.name in ("shift", "send", "recv"):
         def shift(x):
-            n = axis_size(axes[0])
+            n = jax.lax.axis_size(axes[0])
             perm = [(i, (i + 1) % n) for i in range(n)]
             return jax.lax.ppermute(x, axes[0], perm)
         return jax.tree.map(shift, value)

@@ -19,12 +19,11 @@ from __future__ import annotations
 import dataclasses
 
 from .. import ir
-
-_HBM_BYTES_DEFAULT = 16 * 2**30  # TPU v5e
+from ..devices import peaks
 
 
 def plan_memory(prog: ir.Program) -> ir.Program:
-    hbm = ir.ext_get(prog.extensions, "hbm_bytes", _HBM_BYTES_DEFAULT)
+    hbm = ir.ext_get(prog.extensions, "hbm_bytes", peaks().hbm_bytes)
     act = ir.ext_get(prog.extensions, "act_bytes", 0)
     resident = ir.ext_get(prog.extensions, "resident_bytes", 0)
 

@@ -22,10 +22,11 @@ from ..models import api
 from ..optim import make_optimizer
 from . import ir
 from .builder import PlanBuilder
+from .devices import peaks
 from .lower import LoweredPlan, plan_from_program, tree_symbols
 from .passes import run_pipeline
 
-HBM_BYTES = 16 * 2**30          # TPU v5e per chip
+HBM_BYTES = peaks().hbm_bytes     # per chip of the target device
 
 
 # ------------------------------------------------------------- mesh definitions

@@ -15,7 +15,7 @@ def _axpy_kernel(a_ref, x_ref, y_ref, o_ref):
     o_ref[...] = a_ref[0] * x_ref[...] + y_ref[...]
 
 
-def axpy(a, x, y, *, block: int = 1024, interpret: bool = True):
+def axpy(a, x, y, *, block: int = 1024, interpret: bool = False):
     """a: scalar; x/y: [N]. Block must divide N (pad upstream otherwise)."""
     n = x.shape[0]
     block = min(block, n)

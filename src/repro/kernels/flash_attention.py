@@ -65,7 +65,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 
 
 def flash_attention(q, k, v, *, causal: bool = True, bq: int = 256,
-                    bk: int = 256, interpret: bool = True):
+                    bk: int = 256, interpret: bool = False):
     """q/k/v: [B, S, H, hd] (same head count: expand GQA upstream)."""
     B, S, H, hd = q.shape
     bq, bk = min(bq, S), min(bk, S)

@@ -29,7 +29,7 @@ def _mm_kernel(a_ref, b_ref, o_ref, acc_ref, *, k_steps):
 
 
 def matmul(a, b, *, bm: int = 256, bn: int = 256, bk: int = 256,
-           interpret: bool = True):
+           interpret: bool = False):
     """C[M,N] = A[M,K] @ B[K,N]; tile sizes must divide the dims."""
     M, K = a.shape
     K2, N = b.shape

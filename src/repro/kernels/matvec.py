@@ -28,7 +28,7 @@ def _mv_kernel(a_ref, x_ref, o_ref, acc_ref, *, k_steps):
         o_ref[...] = acc_ref[...].astype(o_ref.dtype)
 
 
-def matvec(a, x, *, bm: int = 512, bk: int = 1024, interpret: bool = True):
+def matvec(a, x, *, bm: int = 512, bk: int = 1024, interpret: bool = False):
     """y[M] = A[M,K] @ x[K]."""
     M, K = a.shape
     bm, bk = min(bm, M), min(bk, K)

@@ -22,22 +22,20 @@ from . import ref
 from . import ssm_scan as _ssm_mod
 from . import stencil2d as _st_mod
 
-# jit'd pallas entry points (interpret=True: CPU container; on real TPUs the
-# same call sites compile to Mosaic by flipping interpret)
+# jit'd pallas entry points. ``interpret`` is a static argument of each:
+# False (the default) compiles to Mosaic for the TPU; True runs the Pallas
+# interpreter, which is how CPU-only tests exercise the same kernels.
 
-axpy = jax.jit(functools.partial(_axpy_mod.axpy, interpret=True),
-               static_argnames=("block",))
-matmul = jax.jit(functools.partial(_mm_mod.matmul, interpret=True),
-                 static_argnames=("bm", "bn", "bk"))
-matvec = jax.jit(functools.partial(_mv_mod.matvec, interpret=True),
-                 static_argnames=("bm", "bk"))
-stencil2d = jax.jit(functools.partial(_st_mod.stencil2d, interpret=True),
-                    static_argnames=("w_center", "w_side", "bm", "bn"))
-flash_attention = jax.jit(
-    functools.partial(_fa_mod.flash_attention, interpret=True),
-    static_argnames=("causal", "bq", "bk"))
-ssm_scan = jax.jit(functools.partial(_ssm_mod.ssm_scan, interpret=True),
-                   static_argnames=("chunk",))
+axpy = jax.jit(_axpy_mod.axpy, static_argnames=("block", "interpret"))
+matmul = jax.jit(_mm_mod.matmul, static_argnames=("bm", "bn", "bk",
+                                                  "interpret"))
+matvec = jax.jit(_mv_mod.matvec, static_argnames=("bm", "bk", "interpret"))
+stencil2d = jax.jit(_st_mod.stencil2d,
+                    static_argnames=("w_center", "w_side", "bm", "bn",
+                                     "interpret"))
+flash_attention = jax.jit(_fa_mod.flash_attention,
+                          static_argnames=("causal", "bq", "bk", "interpret"))
+ssm_scan = jax.jit(_ssm_mod.ssm_scan, static_argnames=("chunk", "interpret"))
 
 
 PALLAS: Dict[str, Callable] = {
@@ -59,9 +57,13 @@ REFERENCE: Dict[str, Callable] = {
 }
 
 
-def resolve(fn: str, backend: str = "reference") -> Callable:
-    """UPIR KernelOp resolution: 'pallas' (simd) or 'reference' (worksharing)."""
+def resolve(fn: str, backend: str = "reference", *,
+            interpret: bool = False) -> Callable:
+    """UPIR KernelOp resolution: 'pallas' (simd) or 'reference' (worksharing).
+    ``interpret`` selects the Pallas interpreter for the 'pallas' backend."""
     table = PALLAS if backend == "pallas" else REFERENCE
     if fn not in table:
         raise KeyError(f"kernel '{fn}' not registered for backend '{backend}'")
+    if backend == "pallas":
+        return functools.partial(table[fn], interpret=interpret)
     return table[fn]

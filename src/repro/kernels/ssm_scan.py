@@ -53,7 +53,7 @@ def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, h_ref, *, chunk):
     y_ref[0] = (y_intra + y_inter).astype(y_ref.dtype)
 
 
-def ssm_scan(x, dt, A, Bm, Cm, *, chunk: int = 128, interpret: bool = True):
+def ssm_scan(x, dt, A, Bm, Cm, *, chunk: int = 128, interpret: bool = False):
     """x [B,S,H,P]; dt [B,S,H]; A [H]; Bm/Cm [B,S,N] (G=1).
 
     Returns y [B,S,H,P] (state output is kept in-kernel; the jnp reference

@@ -25,14 +25,14 @@ def _stencil_kernel(u_ref, o_ref, *, bm, bn, w_center, w_side):
     i, j = pl.program_id(0), pl.program_id(1)
     # haloed (bm+2 x bn+2) read at element offset (i*bm, j*bn): block index
     # maps can't express overlapping tiles, so the halo is a dslice load
-    u = pl.load(u_ref, (pl.dslice(i * bm, bm + 2), pl.dslice(j * bn, bn + 2)))
+    u = u_ref[pl.ds(i * bm, bm + 2), pl.ds(j * bn, bn + 2)]
     o_ref[...] = (w_center * u[1:-1, 1:-1]
                   + w_side * (u[:-2, 1:-1] + u[2:, 1:-1]
                               + u[1:-1, :-2] + u[1:-1, 2:])).astype(o_ref.dtype)
 
 
 def stencil2d(u, *, w_center: float = -4.0, w_side: float = 1.0,
-              bm: int = 256, bn: int = 256, interpret: bool = True):
+              bm: int = 256, bn: int = 256, interpret: bool = False):
     """u: [M, N]; zero boundary."""
     M, N = u.shape
     bm, bn = min(bm, M), min(bn, N)

@@ -1,12 +1,11 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run: lower + compile every (architecture x input shape) on the
 production meshes, record memory/cost/roofline evidence.
 
-MUST be run as a module: ``PYTHONPATH=src python -m repro.launch.dryrun --all``
-(the XLA_FLAGS line above executes before any jax import — 512 placeholder host
-devices exist only inside dry-run processes, never in tests/benchmarks).
+MUST be run as a module: ``PYTHONPATH=src python -m repro.launch.dryrun --all``.
+``main()`` sets ``XLA_FLAGS`` to 512 placeholder host devices before its first
+jax import, so they exist only inside dry-run processes: importing this module
+changes no environment, and no test, benchmark or chip run ever sees them.
+This is CPU-only analysis; the chip path never imports it.
 
 Per cell this produces experiments/dryrun/<arch>__<shape>__<mesh>.json with:
   * compiled.memory_analysis()  — per-device bytes (proves the cell fits HBM);
@@ -23,6 +22,7 @@ driver itself).
 import argparse
 import dataclasses
 import json
+import os
 import subprocess
 import sys
 import time
@@ -30,6 +30,7 @@ import traceback
 from pathlib import Path
 
 RESULTS_DIR = Path(__file__).resolve().parents[3] / "experiments" / "dryrun"
+HOST_DEVICES = "--xla_force_host_platform_device_count=512"
 
 
 def run_cell(arch: str, shape_name: str, multi_pod: bool, *,
@@ -166,7 +167,8 @@ def sweep(meshes=("single", "multi"), archs=None, shapes=None,
         print(f"[{i + 1}/{len(todo)}] {arch} x {shape} x {mesh} ...",
               flush=True)
         t0 = time.time()
-        r = subprocess.run(cmd, capture_output=True, text=True, timeout=3600)
+        r = subprocess.run(cmd, capture_output=True, text=True, timeout=3600,
+                           env={**os.environ, "XLA_FLAGS": HOST_DEVICES})
         dt = time.time() - t0
         if r.returncode != 0:
             print(f"  FAILED ({dt:.0f}s):\n{r.stdout[-2000:]}\n{r.stderr[-2000:]}")
@@ -178,6 +180,10 @@ def sweep(meshes=("single", "multi"), archs=None, shapes=None,
 
 
 def main():
+    if "jax" in sys.modules:
+        raise RuntimeError("dryrun must set XLA_FLAGS before jax is imported; "
+                           "run it as `python -m repro.launch.dryrun`")
+    os.environ["XLA_FLAGS"] = HOST_DEVICES
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch")
     ap.add_argument("--shape")

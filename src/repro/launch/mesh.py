@@ -5,24 +5,20 @@ jax device state — the dry-run sets XLA_FLAGS before any jax import.
 """
 from __future__ import annotations
 
-from typing import Optional, Tuple
+from typing import Tuple
 
 import jax
-
-
-def _axis_type_kwargs(n: int) -> dict:
-    """axis_types=(Auto,)*n on jax versions that have AxisType; {} on older
-    jax (<= 0.4.x), where every mesh axis is implicitly Auto."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    return {"axis_types": (axis_type.Auto,) * n} if axis_type is not None else {}
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 single-pod (256 chips) or 2x16x16 two-pod (512 chips) mesh."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_axis_type_kwargs(len(axes)))
+    return make_mesh(shape, axes)
 
 
 def make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]):
-    return jax.make_mesh(shape, axes, **_axis_type_kwargs(len(axes)))
+    """Mesh over ``jax.devices()`` whose axes are all ``Auto`` (GSPMD decides
+    what it is not told)."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))

@@ -12,7 +12,8 @@ accumulates three terms per device:
   * collective bytes — ring-model per-device bytes for all-gather / all-reduce /
                  reduce-scatter / all-to-all / collective-permute.
 
-Hardware constants (TPU v5e): 197 TFLOP/s bf16, 819 GB/s HBM, ~50 GB/s/link ICI.
+Hardware constants come from ``core.devices`` (the v5e row: 197 TFLOP/s bf16,
+819 GB/s HBM, 50 GB/s per ICI link).
 """
 from __future__ import annotations
 
@@ -21,9 +22,12 @@ import re
 from collections import defaultdict
 from typing import Dict, List, Optional, Tuple
 
-PEAK_FLOPS = 197e12
-HBM_BW = 819e9
-LINK_BW = 50e9
+from ..core.devices import peaks
+
+_TARGET = peaks()
+PEAK_FLOPS = _TARGET.flops_bf16
+HBM_BW = _TARGET.hbm_bw
+LINK_BW = _TARGET.link_bw
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "f16": 2, "bf16": 2,

@@ -1,7 +1,12 @@
 """Serving launcher: continuous-batching engine over the UPIR decode plan.
 
-    PYTHONPATH=src python -m repro.launch.serve --arch tinyllama-1.1b --smoke \
+    PYTHONPATH=src python -m repro.launch.serve --arch tinyllama-1.1b \
         --requests 8 --slots 4 --prompt-len 16 --tokens 16
+
+serves the published widths on the default device (a TPU); add ``--smoke``
+for the reduced config that a CPU-only run can afford (with
+``JAX_PLATFORMS=cpu``). The persistent compile cache is placed by
+``launch.compile_cache`` (``JAX_COMPILATION_CACHE_DIR``, else ``.jax_cache/``).
 
 Requests enter the engine's admission queue; prefill fills free decode slots
 and a fixed-width decode batch advances every active sequence one token per
@@ -49,7 +54,6 @@ with telemetry on or off; the traced engine's UPIR program fingerprints
 apart (``mm(traced)`` + ``upir.trace_emit``).
 
 ``--sequential`` also runs the old one-request-at-a-time path for comparison.
-On the CPU container use --smoke.
 """
 import argparse
 
@@ -150,6 +154,9 @@ def main():
     import numpy as np
 
     import jax
+
+    from .compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     from ..configs import config, smoke_config
     from ..models import api

@@ -124,8 +124,7 @@ def moe_apply_ep(p, x, *, top_k: int, capacity_factor: float, act, glu: bool,
     lowering of the UPIR ``sync all_to_all`` node.
     """
     from .layers import _act
-    from ..core.lower import axis_size
-    n_shards = axis_size(axis)
+    n_shards = jax.lax.axis_size(axis)
     B, S, D = x.shape
     E_local = p["w1"].shape[0]            # experts per shard
     E = E_local * n_shards

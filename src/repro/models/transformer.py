@@ -578,7 +578,7 @@ class KernelSpec:
     leaking through every ``decode_step_paged`` call signature."""
 
     attn_impl: str = "xla"         # xla (gather) | pallas (paged-attention)
-    interpret: bool = True         # Pallas interpreter mode (CPU containers)
+    interpret: bool = False        # Pallas interpreter (CPU-only runs)
 
     def __post_init__(self):
         if self.attn_impl not in ("xla", "pallas"):

@@ -131,6 +131,19 @@ from .speculative import SpecConfig, SpeculativeDecoder
 from .telemetry import Telemetry
 from .tiered import HostPagePool
 
+
+def snapshot(host: np.ndarray) -> jax.Array:
+    """Upload host state that the engine keeps mutating in place.
+
+    ``jnp.asarray`` of a NumPy array may read the host buffer after it
+    returns: jaxlib's CPU client finishes the transfer on a worker thread
+    when the backend is busy with dispatched steps. An in-place update right
+    after the call (``pos[i] += 1``, a page-table write, an admission's
+    policy row) can then leak into the step that was just dispatched. A
+    private copy that nothing else references makes the device value a
+    snapshot of the host state at dispatch time."""
+    return jnp.asarray(np.array(host, copy=True))
+
 # ----------------------------------------------------------------- requests
 
 
@@ -308,8 +321,8 @@ class EngineConfig:
       with decode (chunked prefill; cuts tail TTFT under long prompts).
     * ``decode_kernel`` / ``interpret`` — paged decode attention
       implementation (``"xla"`` gather or the ``"pallas"`` paged-attention
-      kernel, optionally interpreted on CPU); validated here once as a
-      ``KernelSpec`` and keyed into the paged jit entries.
+      kernel, compiled for the TPU unless ``interpret``); validated here
+      once as a ``KernelSpec`` and keyed into the paged jit entries.
     * ``prefix_cache`` *[plan key]* — paged mode only: automatic prefix
       caching with ref-counted page sharing and copy-on-write (adds
       ``mm(shared_prefix)`` + ``share``/``cow`` MemOps to the program).
@@ -394,7 +407,7 @@ class EngineConfig:
     num_pages: int = 0                 # allocatable pages; 0 = slots*ceil(max_seq/page_size)
     prefill_chunk: int = 0             # 0 = one-shot prefill; else chunk length
     decode_kernel: str = "xla"         # xla (gather) | pallas (paged-attention kernel)
-    interpret: bool = True             # Pallas interpreter mode (CPU containers)
+    interpret: bool = False            # Pallas interpreter (CPU-only runs)
     prefix_cache: bool = False         # paged only: share prompt-prefix pages
     # ---- tiered KV + disaggregated prefill/decode (runtime.tiered)
     tiered_kv: bool = False            # spill cold prefix pages to host tier
@@ -1819,7 +1832,7 @@ class Engine:
         toks = self._padded_prompt(req)[offset:]
         s = req.sampling or GREEDY
         nxt, logits, self.pool = fn(
-            self.params, self.pool, jnp.asarray(row),
+            self.params, self.pool, snapshot(row),
             jnp.asarray(toks)[None, :], jnp.asarray(ids, jnp.int32),
             jnp.asarray(req._key), jnp.float32(s.temperature),
             jnp.int32(s.top_k), jnp.float32(s.top_p))
@@ -1932,7 +1945,7 @@ class Engine:
             t_c = time.perf_counter() if self.telemetry is not None else None
             pool_in = self.prefill_pool if self.disagg else self.pool
             nxt, logits, pool_out = self._chunk_prefill(
-                self.params, pool_in, jnp.asarray(row),
+                self.params, pool_in, snapshot(row),
                 jnp.asarray(toks)[None, :], jnp.int32(off),
                 jnp.asarray(ids, jnp.int32), jnp.asarray(req._key),
                 jnp.float32(s.temperature), jnp.int32(s.top_k),
@@ -2254,7 +2267,7 @@ class Engine:
             self._poison_np[f.slot] = True
             self._poison_dev = None
         if self._poison_dev is None:
-            self._poison_dev = jnp.asarray(self._poison_np)
+            self._poison_dev = snapshot(self._poison_np)
         return self._poison_dev
 
     def _nan_poll(self, active) -> None:
@@ -2545,10 +2558,10 @@ class Engine:
             # nothing but the position vector
             if self._policy_dev is None:
                 self._policy_dev = (
-                    jnp.asarray(self.keys_np), jnp.asarray(self.temps_np),
-                    jnp.asarray(self.topks_np), jnp.asarray(self.topps_np),
-                    jnp.asarray(self.eos_np), jnp.asarray(self.presence_np),
-                    jnp.asarray(self.frequency_np))
+                    snapshot(self.keys_np), snapshot(self.temps_np),
+                    snapshot(self.topks_np), snapshot(self.topps_np),
+                    snapshot(self.eos_np), snapshot(self.presence_np),
+                    snapshot(self.frequency_np))
             if self._spec is not None and not self.degraded:
                 try:
                     self._maybe_raise("verify")
@@ -2567,13 +2580,13 @@ class Engine:
                         out = self._decode(
                             self.params, self.pool,
                             self._device_page_table(), self.tokens,
-                            jnp.asarray(self.pos), keys, temps, topks,
+                            snapshot(self.pos), keys, temps, topks,
                             topps, eos, self.finished, self.counts,
                             presence, frequency, poison, self.poisoned)
                     else:
                         out = self._decode(
                             self.params, self.cache, self.tokens,
-                            jnp.asarray(self.pos), keys, temps, topks,
+                            snapshot(self.pos), keys, temps, topks,
                             topps, eos, self.finished, self.counts,
                             presence, frequency, poison, self.poisoned)
                 except Exception as e:   # noqa: BLE001 — FT quarantine
@@ -2658,7 +2671,7 @@ class Engine:
         so only accepted tokens stay committed."""
         keys, temps, topks, topps, _eos, _presence, _frequency = \
             self._policy_dev
-        pos_dev = jnp.asarray(self.pos)
+        pos_dev = snapshot(self.pos)
         if self.paged:
             out, n_acc, self.pool, self._spec.cache = self._spec._step(
                 self.params, self._spec.params, self.pool,

@@ -23,10 +23,9 @@ from typing import Callable, Dict, Optional
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from ..configs.base import ArchConfig
-from ..core.lower import LoweredPlan, axis_size
+from ..core.lower import LoweredPlan
 from ..models import api
 from ..optim import clip_by_global_norm, cosine_warmup, make_optimizer
 from . import compression as comp
@@ -95,7 +94,7 @@ def make_explicit_train_step(cfg: ArchConfig, plan: LoweredPlan, mesh: Mesh,
             else:
                 grads = jax.tree.map(lambda g: jax.lax.psum(g, data_axis),
                                      grads)
-        n_data = axis_size(data_axis)
+        n_data = jax.lax.axis_size(data_axis)
         grads = jax.tree.map(lambda g: g / n_data, grads)
 
         grads, gnorm = clip_by_global_norm(grads, grad_clip)
@@ -115,7 +114,7 @@ def make_explicit_train_step(cfg: ArchConfig, plan: LoweredPlan, mesh: Mesh,
         return jax.tree.map(lambda _: batch_spec, batch)
 
     def step(state, batch, residual):
-        body = shard_map(
+        body = jax.shard_map(
             shard_body, mesh=mesh,
             in_specs=(jax.tree.map(lambda _: rep, state),
                       batch_specs_for(batch),
@@ -123,7 +122,7 @@ def make_explicit_train_step(cfg: ArchConfig, plan: LoweredPlan, mesh: Mesh,
             out_specs=(jax.tree.map(lambda _: rep, state),
                        {"loss": rep, "grad_norm": rep, "lr": rep},
                        jax.tree.map(lambda _: rep, residual)),
-            check_rep=False)
+            check_vma=False)
         return body(state, batch, residual)
 
     return jax.jit(step)

@@ -35,20 +35,6 @@ from ..optim import clip_by_global_norm, cosine_warmup, make_optimizer
 SCANNED_SUBTREES = ("blocks", "mamba", "enc_blocks", "dec_blocks")
 
 
-def _shard_map_manual(f, mesh, in_specs, out_specs, manual_axes):
-    """shard_map with only ``manual_axes`` manual, across jax versions:
-    jax.shard_map(axis_names=...) on new jax, the experimental API with
-    ``auto=`` (the complement) on <= 0.4.x."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs,
-                             axis_names=set(manual_axes), check_vma=False)
-    from jax.experimental.shard_map import shard_map
-    auto = frozenset(mesh.axis_names) - frozenset(manual_axes)
-    return shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                     check_rep=False, auto=auto)
-
-
 def _data_only(spec: P) -> P:
     """Keep only 'data' components of a spec (manual axis placement)."""
     return P(*[("data" if e == "data" or
@@ -135,13 +121,13 @@ def make_fsdp_train_step(cfg: ArchConfig, plan: LoweredPlan, mesh, *,
 
     def train_step(state, batch):
         params = state["params"]
-        loss_val, grads = _shard_map_manual(
-            grads_body,
-            mesh,
-            (param_in_specs,
-             jax.tree.map(lambda _: batch_spec_manual, batch)),
-            (P(), param_in_specs),
-            ("data",),
+        # only 'data' is manual; 'model' stays automatic (GSPMD)
+        loss_val, grads = jax.shard_map(
+            grads_body, mesh=mesh,
+            in_specs=(param_in_specs,
+                      jax.tree.map(lambda _: batch_spec_manual, batch)),
+            out_specs=(P(), param_in_specs),
+            axis_names={"data"}, check_vma=False,
         )(params, batch)
         grads = jax.tree.map(lambda g: g.astype(jnp.float32), grads)
         grads, gnorm = clip_by_global_norm(grads, grad_clip)

@@ -121,7 +121,6 @@ def test_pipeline_parallel_ppermute(subproc):
     subproc("""
 import jax, jax.numpy as jnp, numpy as np
 from functools import partial
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.launch.mesh import make_mesh
@@ -176,8 +175,8 @@ def pipelined(w_stage, x_mb):
 
 x = jax.random.normal(jax.random.key(1), (B, D)) * 0.5
 x_mb = x.reshape(MB, B // MB, D)
-f = shard_map(pipelined, mesh=mesh, in_specs=(P("stage"), P()),
-              out_specs=P(), check_rep=False)
+f = jax.shard_map(pipelined, mesh=mesh, in_specs=(P("stage"), P()),
+                  out_specs=P(), check_vma=False)
 out = f(Ws.reshape(4, 1, D, D), x_mb)
 ref = seq_model(x).reshape(MB, B // MB, D)
 np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=2e-5, atol=2e-5)

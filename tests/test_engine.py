@@ -12,7 +12,8 @@ from repro.core.passes import run_pipeline
 from repro.core.plans import build_program
 from repro.core.printer import program_fingerprint
 from repro.models import api
-from repro.runtime.engine import Engine, EngineConfig, serve_sequential
+from repro.runtime.engine import (Engine, EngineConfig, RequestSpec,
+                                  serve_sequential)
 
 CFG = smoke_config("tinyllama-1.1b")
 BUCKET = 8
@@ -201,7 +202,8 @@ def mk_paged(params, cfg=CFG, slots=2, num_pages=0, prefill_chunk=0,
                                     max_seq=MAX_SEQ, kv_layout="paged",
                                     page_size=PAGE, num_pages=num_pages,
                                     prefill_chunk=prefill_chunk,
-                                    decode_kernel=decode_kernel),
+                                    decode_kernel=decode_kernel,
+                                    interpret=True),
                   params=params, plan_cache=PlanCache())
 
 
@@ -216,6 +218,47 @@ def mixed_workload(n=6, seed=3):
     return [(rng.integers(0, CFG.vocab, size=int(rng.integers(1, BUCKET + 1))
                           ).tolist(), int(rng.integers(1, TOKENS + 1)))
             for _ in range(n)]
+
+
+@pytest.mark.parametrize("layout", ["dense", "paged_chunked"])
+def test_fresh_engines_serve_identical_streams(params, layout):
+    """Async dispatch stays on: the host mutates its position vector, page
+    tables and policy rows in place right after dispatching a step, so every
+    upload must be a snapshot. Before that, four fresh engines serving this
+    workload gave four different greedy streams."""
+    work = mixed_workload(5, seed=9)
+    seq = serve_sequential(CFG, params, [RequestSpec(prompt=tuple(p),
+                                                     max_new_tokens=n)
+                                         for p, n in work],
+                           max_seq=MAX_SEQ, prompt_buckets=(BUCKET,))
+    want = [seq["tokens"][i + 1] for i in range(len(work))]
+    # one plan cache: only the first engine compiles, so the later ones
+    # dispatch back to back, which is when the host ran ahead of uploads
+    cache = PlanCache()
+    kw = dict(kv_layout="paged", page_size=PAGE, prefill_chunk=PAGE,
+              interpret=True) if layout == "paged_chunked" else {}
+    for _ in range(5):
+        engine = Engine(CFG, EngineConfig(slots=2, prompt_buckets=(BUCKET,),
+                                          max_seq=MAX_SEQ, **kw),
+                        params=params, plan_cache=cache)
+        streams, _ = run_streams(engine, work)
+        assert streams == want
+
+
+def test_snapshot_is_immune_to_host_mutation_under_load():
+    """The engine's upload seam: on a busy CPU backend, ``jnp.asarray`` of a
+    NumPy array can still be reading the host buffer after it returns, so a
+    write right after the call may reach the device value. ``snapshot``
+    copies first; the device value is the host state at the call."""
+    from repro.runtime.engine import snapshot
+    busy = jax.jit(lambda m: (m @ m @ m).sum())
+    m = jnp.ones((768, 768))
+    for _ in range(40):
+        busy(m)
+        host = np.zeros((8,), np.int32)
+        dev = snapshot(host)
+        host[:] = 99
+        assert int(dev[0]) == 0
 
 
 def test_paged_engine_matches_dense_tokens(params):

@@ -1,15 +1,5 @@
 """Explicit-FSDP (shard_map, manual 'data' axis) trainer: the T3 structural
 fix — per-layer gradients born sharded via the AD of tiled all_gather."""
-import jax
-import pytest
-
-# Partial-manual shard_map (manual 'data', auto 'model') crashes the XLA
-# bundled with jax <= 0.4.x (Check failed: sharding.IsManualSubgroup()).
-# jax.shard_map's presence marks the versions where it works.
-pytestmark = pytest.mark.skipif(
-    not hasattr(jax, "shard_map"),
-    reason="partial-manual shard_map requires jax >= 0.5 "
-           "(XLA IsManualSubgroup crash on older jax)")
 
 
 def test_fsdp_step_compiles_with_reduce_scatter(subproc):

@@ -22,7 +22,7 @@ TOL = {jnp.float32: dict(rtol=2e-4, atol=2e-5),
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_axpy(n, block, dtype):
     x, y = rnd((n,), dtype, seed=1), rnd((n,), dtype, seed=2)
-    out = ops.axpy(2.5, x, y, block=block)
+    out = ops.axpy(2.5, x, y, block=block, interpret=True)
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(ref.axpy(jnp.asarray(2.5, dtype), x, y),
                                           np.float32), **TOL[dtype])
@@ -36,7 +36,7 @@ def test_axpy(n, block, dtype):
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_matmul(m, k, n, bm, bn, bk, dtype):
     a, b = rnd((m, k), dtype, 0.3, 3), rnd((k, n), dtype, 0.3, 4)
-    out = ops.matmul(a, b, bm=bm, bn=bn, bk=bk)
+    out = ops.matmul(a, b, bm=bm, bn=bn, bk=bk, interpret=True)
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(ref.matmul(a, b), np.float32),
                                **TOL[dtype])
@@ -45,15 +45,15 @@ def test_matmul(m, k, n, bm, bn, bk, dtype):
 @pytest.mark.parametrize("m,k", [(512, 512), (1024, 256)])
 def test_matvec(m, k):
     a, x = rnd((m, k), seed=5), rnd((k,), seed=6)
-    out = ops.matvec(a, x, bm=256, bk=256)
+    out = ops.matvec(a, x, bm=256, bk=256, interpret=True)
     np.testing.assert_allclose(out, ref.matvec(a, x), rtol=2e-4, atol=2e-5)
 
 
 @pytest.mark.parametrize("m,n,bm,bn", [(256, 256, 128, 128), (128, 384, 64, 128)])
 def test_stencil(m, n, bm, bn):
     u = rnd((m, n), seed=7)
-    np.testing.assert_allclose(ops.stencil2d(u, bm=bm, bn=bn), ref.stencil2d(u),
-                               rtol=2e-5, atol=2e-5)
+    np.testing.assert_allclose(ops.stencil2d(u, bm=bm, bn=bn, interpret=True),
+                               ref.stencil2d(u), rtol=2e-5, atol=2e-5)
 
 
 @pytest.mark.parametrize("B,S,H,hd,bq,bk", [
@@ -65,7 +65,7 @@ def test_flash_attention(B, S, H, hd, bq, bk, causal):
     q = rnd((B, S, H, hd), scale=0.3, seed=8)
     k = rnd((B, S, H, hd), scale=0.3, seed=9)
     v = rnd((B, S, H, hd), scale=0.3, seed=10)
-    out = ops.flash_attention(q, k, v, causal=causal, bq=bq, bk=bk)
+    out = ops.flash_attention(q, k, v, causal=causal, bq=bq, bk=bk, interpret=True)
     np.testing.assert_allclose(out, ref.flash_attention(q, k, v, causal=causal),
                                rtol=2e-4, atol=2e-4)
 
@@ -75,7 +75,7 @@ def test_flash_attention_matches_model_chunked():
     q = rnd((2, 256, 4, 32), scale=0.3, seed=11)
     k = rnd((2, 256, 4, 32), scale=0.3, seed=12)
     v = rnd((2, 256, 4, 32), scale=0.3, seed=13)
-    a = ops.flash_attention(q, k, v, causal=True, bq=64, bk=64)
+    a = ops.flash_attention(q, k, v, causal=True, bq=64, bk=64, interpret=True)
     b = attention_chunked(q, k, v, causal=True, q_chunk=64, kv_chunk=64)
     np.testing.assert_allclose(a, b, rtol=2e-4, atol=2e-4)
 
@@ -90,7 +90,7 @@ def test_ssm_scan(B, S, H, P, N, chunk):
     A = -jnp.exp(jax.random.uniform(jax.random.key(16), (H,), maxval=1.0))
     Bm = rnd((B, S, N), scale=0.5, seed=17)
     Cm = rnd((B, S, N), scale=0.5, seed=18)
-    y = ops.ssm_scan(x, dt, A, Bm, Cm, chunk=chunk)
+    y = ops.ssm_scan(x, dt, A, Bm, Cm, chunk=chunk, interpret=True)
     y_ref, _ = ref.ssm_chunk_scan(x, dt, A, Bm, Cm)
     np.testing.assert_allclose(y, y_ref, rtol=2e-3, atol=2e-3)
 
@@ -103,7 +103,7 @@ def test_ssm_scan(B, S, H, P, N, chunk):
 def test_axpy_any_blockcount(nblocks, scale):
     n = 128 * nblocks
     x, y = rnd((n,), seed=20), rnd((n,), seed=21)
-    out = ops.axpy(float(scale), x, y, block=128)
+    out = ops.axpy(float(scale), x, y, block=128, interpret=True)
     np.testing.assert_allclose(out, ref.axpy(float(scale), x, y),
                                rtol=2e-5, atol=2e-5)
 
@@ -113,7 +113,7 @@ def test_axpy_any_blockcount(nblocks, scale):
 @settings(max_examples=10, deadline=None)
 def test_matmul_shape_sweep(m, k, n):
     a, b = rnd((m, k), scale=0.3, seed=22), rnd((k, n), scale=0.3, seed=23)
-    out = ops.matmul(a, b, bm=128, bn=128, bk=128)
+    out = ops.matmul(a, b, bm=128, bn=128, bk=128, interpret=True)
     np.testing.assert_allclose(out, ref.matmul(a, b), rtol=2e-4, atol=2e-4)
 
 

@@ -131,3 +131,18 @@ def test_printer_renders_model_plan():
     assert "taskloop" in text                          # microbatching
     assert "upir.sync" in text
     assert "distribute(dim(" in text                   # data distributions
+
+
+def test_device_peaks_table_has_no_default():
+    from repro.core.devices import PEAKS, TARGET_KIND, peaks
+    from repro.launch import roofline
+    v5e = peaks("TPU v5 lite")
+    assert v5e is PEAKS[TARGET_KIND]
+    assert (v5e.flops_bf16, v5e.hbm_bw, v5e.hbm_bytes) == \
+        (197e12, 819e9, 16 * 2**30)
+    # every consumer reads the one table
+    assert plans.HBM_BYTES == v5e.hbm_bytes
+    assert (roofline.PEAK_FLOPS, roofline.HBM_BW, roofline.LINK_BW) == \
+        (v5e.flops_bf16, v5e.hbm_bw, v5e.link_bw)
+    with pytest.raises(KeyError, match="no peak rates"):
+        peaks("cpu")
