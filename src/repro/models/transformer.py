@@ -629,7 +629,9 @@ def decode_step_paged(cfg: ArchConfig, params, pool, page_table, tokens, pos,
     READ-ONLY per layer, attention gathers K/V through the page table
     (``kernel.attn_impl='pallas'`` streams physical pages in the Pallas
     kernel instead), and the new token's K/V is scattered into its page
-    once, post-scan.
+    once, post-scan. The gather and attention, the MLP and the scatter run
+    under the named scopes ``attention``, ``mlp`` and ``kv_write``, so a
+    device profile can attribute the step's time to them.
     """
     _check_paged(cfg)
     kernel = kernel or KernelSpec()
@@ -649,28 +651,31 @@ def decode_step_paged(cfg: ArchConfig, params, pool, page_table, tokens, pos,
             .reshape(B, 1, KV, hd)
         q = apply_rope(q, pos[:, None], cfg.rope_theta)
         k = apply_rope(k, pos[:, None], cfg.rope_theta)
-        if kernel.attn_impl == "pallas":
-            from ..kernels.paged_attention import paged_attention_decode
-            o = paged_attention_decode(q, k_pg, v_pg, page_table, pos,
-                                       new_kv=(k, v),
-                                       interpret=kernel.interpret)
-        else:
-            o = attention_decode_paged(q, k_pg, v_pg, page_table, pos,
-                                       new_kv=(k, v))
+        with jax.named_scope("attention"):
+            if kernel.attn_impl == "pallas":
+                from ..kernels.paged_attention import paged_attention_decode
+                o = paged_attention_decode(q, k_pg, v_pg, page_table, pos,
+                                           new_kv=(k, v),
+                                           interpret=kernel.interpret)
+            else:
+                o = attention_decode_paged(q, k_pg, v_pg, page_table, pos,
+                                           new_kv=(k, v))
         a = jnp.einsum("bsh,hd->bsd", o.reshape(B, 1, H * hd).astype(dtype),
                        p_l["wo"].astype(dtype))
         x = x + a.astype(x.dtype)
-        m, _ = _mlp_or_moe(cfg, p_l, x, dtype)
+        with jax.named_scope("mlp"):
+            m, _ = _mlp_or_moe(cfg, p_l, x, dtype)
         return constrain(x + m.astype(x.dtype), "hidden"), (k, v)
 
     x, (k_steps, v_steps) = jax.lax.scan(
         body, x, (params["blocks"], pool["k_pages"], pool["v_pages"]))
-    new_pool = {
-        "k_pages": cache_insert_paged(pool["k_pages"], k_steps, page_table,
-                                      pos),
-        "v_pages": cache_insert_paged(pool["v_pages"], v_steps, page_table,
-                                      pos),
-    }
+    with jax.named_scope("kv_write"):
+        new_pool = {
+            "k_pages": cache_insert_paged(pool["k_pages"], k_steps,
+                                          page_table, pos),
+            "v_pages": cache_insert_paged(pool["v_pages"], v_steps,
+                                          page_table, pos),
+        }
     hidden = norm(x, params["final_norm"], cfg.norm)
     return logits_fn(cfg, params, hidden), new_pool
 
@@ -704,13 +709,15 @@ def prefill_chunk(cfg: ArchConfig, params, pool, page_row, tokens, offset):
             .reshape(B, C, KV, hd)
         q = apply_rope(q, positions, cfg.rope_theta)
         k = apply_rope(k, positions, cfg.rope_theta)
-        k_ctx = gather_kv_pages(k_pg, page_row[None, :])       # [1,P*PS,..]
-        v_ctx = gather_kv_pages(v_pg, page_row[None, :])
-        o = attention_prefill_chunk(q, k_ctx, v_ctx, k, v, off_b)
+        with jax.named_scope("attention"):
+            k_ctx = gather_kv_pages(k_pg, page_row[None, :])   # [1,P*PS,..]
+            v_ctx = gather_kv_pages(v_pg, page_row[None, :])
+            o = attention_prefill_chunk(q, k_ctx, v_ctx, k, v, off_b)
         a = jnp.einsum("bsh,hd->bsd", o.reshape(B, C, H * hd).astype(dtype),
                        p_l["wo"].astype(dtype))
         x = x + a.astype(x.dtype)
-        m, _ = _mlp_or_moe(cfg, p_l, x, dtype)
+        with jax.named_scope("mlp"):
+            m, _ = _mlp_or_moe(cfg, p_l, x, dtype)
         return constrain(x + m.astype(x.dtype), "hidden"), (k, v)
 
     x, (k_steps, v_steps) = jax.lax.scan(

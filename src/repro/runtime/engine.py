@@ -128,7 +128,7 @@ from .scheduling import (FIFO, SchedulerState, SchedulingPolicy,
                          backoff_eligible, note_preemption, select_index,
                          victim as policy_victim, wants_preemption)
 from .speculative import SpecConfig, SpeculativeDecoder
-from .telemetry import Telemetry
+from .telemetry import Telemetry, span
 from .tiered import HostPagePool
 
 
@@ -143,6 +143,25 @@ def snapshot(host: np.ndarray) -> jax.Array:
     private copy that nothing else references makes the device value a
     snapshot of the host state at dispatch time."""
     return jnp.asarray(np.array(host, copy=True))
+
+
+# The engine's compiled programs and their roles, keyed by the name of the
+# function each builder hands to ``jax.jit``. XLA names a program's module
+# ``jit_<name>``, and a device profile attributes time to a role by that
+# module name, so these function names are part of the engine's interface:
+# a rename is an edit here (the encoder ``enc`` and the prefix-hit sampler
+# ``fn`` are not attributed).
+PROGRAM_ROLES: Dict[str, str] = {
+    "step": "decode",       # _build_decode, _build_decode_paged
+    "chunk": "prefill",     # _build_chunk_prefill
+    "pre": "prefill",       # _prefill_fn: one-shot prefill of a bucket
+    "sfx": "prefill",       # _build_suffix_prefill: after a prefix hit
+    "ins": "cache",         # _build_page_insert
+    "insert": "cache",      # _build_insert (api.build_cache_insert)
+    "cp": "cache",          # _build_page_copy: copy-on-write
+    "up": "cache",          # _build_page_upload: tiered page-in
+    "xfer": "cache",        # _build_kv_transfer: prefill -> decode pool
+}
 
 # ----------------------------------------------------------------- requests
 
@@ -226,8 +245,10 @@ class Request:
     Engine-filled observability fields: ``state`` (``new | queued |
     prefilling | active | done | rejected``), ``reason`` (rejection text or
     ``"eos"``), ``bucket`` (padded prompt length), ``slot``, ``tokens_out``
-    (via :meth:`Engine.finalize_request`) and the ``t_submit`` / ``t_first``
-    / ``t_done`` timestamps (TTFT = ``t_first - t_submit``; wall-clock exact
+    (via :meth:`Engine.finalize_request`) and the ``t_submit`` /
+    ``t_admitted`` / ``t_first`` / ``t_done`` timestamps on the host's
+    ``perf_counter`` clock (queue wait = ``t_admitted - t_submit``, stamped at
+    the latest admission; TTFT = ``t_first - t_submit``, wall-clock exact
     only under ``run(sync_per_step=True)``).
     """
 
@@ -248,6 +269,7 @@ class Request:
     slot: int = -1                 # decode slot while active
     tokens_out: List[int] = dataclasses.field(default_factory=list)
     t_submit: float = 0.0
+    t_admitted: float = 0.0        # bound to a slot (latest admission)
     t_first: float = 0.0           # first token produced (TTFT = t_first - t_submit)
     t_done: float = 0.0
     # engine-internal countdown of decode steps remaining
@@ -756,6 +778,9 @@ class EngineStats:
     slo_missed: int = 0
     slo_attainment: Optional[float] = None     # None until a deadline ends
     slo_by_class: Dict[int, float] = dataclasses.field(default_factory=dict)
+    # steps whose admission left the queue waiting because every slot was
+    # busy (paged engines also count a pool short of pages apart)
+    admit_wait_slots: int = 0
     rejected_queue_full: int = 0   # typed REJECTED_QUEUE_FULL admissions
     shed_deadline: int = 0         # typed SHED_DEADLINE load shedding
     plan_cache: Dict[str, Any] = dataclasses.field(default_factory=dict)
@@ -766,6 +791,9 @@ class EngineStats:
     peak_pages: Optional[int] = None
     evictions: Optional[int] = None
     prefill_chunks: Optional[int] = None
+    # steps whose admission left the queue waiting because the pool held
+    # fewer free pages than the head's prompt plus the growth reserve
+    admit_wait_pages: Optional[int] = None
     # ---- prefix-cache section
     prefix_hits: Optional[int] = None
     prefix_full_hits: Optional[int] = None
@@ -1142,17 +1170,21 @@ class Engine:
                  fin, counts, presence, frequency, poison, bad):
             logits, cache = api.decode_step(cfg, params, cache,
                                             {"tokens": tokens, "pos": pos})
-            # the step's input token is the previously emitted one (prefill's
-            # first token on the first step): count it before selecting, so
-            # the penalty at position p sees every token emitted before p
-            counts = counts.at[jnp.arange(counts.shape[0]),
-                               tokens[:, 0]].add(1)
-            # fault injection + sticky finite guard; all-False poison is a
-            # bitwise identity, so non-FT engines pay only a fused mask op
-            lg, bad = poison_and_guard(logits[:, -1], poison, bad)
-            nxt, fin = decode_select(lg, keys, pos, temps, topks,
-                                     eos, fin, top_ps=topps, counts=counts,
-                                     presence=presence, frequency=frequency)
+            with jax.named_scope("sample"):
+                # the step's input token is the previously emitted one
+                # (prefill's first token on the first step): count it before
+                # selecting, so the penalty at position p sees every token
+                # emitted before p
+                counts = counts.at[jnp.arange(counts.shape[0]),
+                                   tokens[:, 0]].add(1)
+                # fault injection + sticky finite guard; all-False poison is
+                # a bitwise identity, so non-FT engines pay only a fused
+                # mask op
+                lg, bad = poison_and_guard(logits[:, -1], poison, bad)
+                nxt, fin = decode_select(lg, keys, pos, temps, topks, eos,
+                                         fin, top_ps=topps, counts=counts,
+                                         presence=presence,
+                                         frequency=frequency)
             return nxt, fin, cache, counts, bad
 
         return jax.jit(step, donate_argnums=(1, 10))
@@ -1165,12 +1197,14 @@ class Engine:
             logits, pool = api.decode_step_paged(
                 cfg, params, pool, page_table,
                 {"tokens": tokens, "pos": pos}, kernel=kernel)
-            counts = counts.at[jnp.arange(counts.shape[0]),
-                               tokens[:, 0]].add(1)
-            lg, bad = poison_and_guard(logits[:, -1], poison, bad)
-            nxt, fin = decode_select(lg, keys, pos, temps, topks,
-                                     eos, fin, top_ps=topps, counts=counts,
-                                     presence=presence, frequency=frequency)
+            with jax.named_scope("sample"):
+                counts = counts.at[jnp.arange(counts.shape[0]),
+                                   tokens[:, 0]].add(1)
+                lg, bad = poison_and_guard(logits[:, -1], poison, bad)
+                nxt, fin = decode_select(lg, keys, pos, temps, topks, eos,
+                                         fin, top_ps=topps, counts=counts,
+                                         presence=presence,
+                                         frequency=frequency)
             return nxt, fin, pool, counts, bad
 
         return jax.jit(step, donate_argnums=(1, 11))
@@ -1316,18 +1350,19 @@ class Engine:
         buffer row* — the per-slot buffer is the source of cross-attention
         memory, not a side copy. Returns (first token [1],
         last-position logits [1, V], cache-of-one)."""
-        toks = jnp.asarray(self._padded_prompt(req))[None, :]
-        memory = jnp.zeros((1, 0, 0), jnp.float32)   # unused placeholder
-        if self.spec.needs_encoder_memory:
-            mem = self._encode(self.params,
-                               jnp.asarray(req.encoder_input)[None])
-            self.enc_memory = self.enc_memory.at[i].set(mem[0])
-            memory = self.enc_memory[i][None]
         s = req.sampling or GREEDY
-        return self._prefill_fn(req.bucket)(
-            self.params, toks, memory, jnp.asarray(req._key),
-            jnp.float32(s.temperature), jnp.int32(s.top_k),
-            jnp.float32(s.top_p))
+        fn = self._prefill_fn(req.bucket)
+        with span("engine.prefill"), span("engine.dispatch"):
+            toks = jnp.asarray(self._padded_prompt(req))[None, :]
+            memory = jnp.zeros((1, 0, 0), jnp.float32)   # unused placeholder
+            if self.spec.needs_encoder_memory:
+                mem = self._encode(self.params,
+                                   jnp.asarray(req.encoder_input)[None])
+                self.enc_memory = self.enc_memory.at[i].set(mem[0])
+                memory = self.enc_memory[i][None]
+            return fn(self.params, toks, memory, jnp.asarray(req._key),
+                      jnp.float32(s.temperature), jnp.int32(s.top_k),
+                      jnp.float32(s.top_p))
 
     def _page_count(self, tokens: int) -> int:
         return -(-tokens // self.ecfg.page_size)
@@ -1383,13 +1418,15 @@ class Engine:
                 "mode: the verify step scores k+1 positions against one "
                 "count snapshot, which breaks the per-position penalty law")
         self._rid += 1
+        with span("engine.readback"):     # the key is built on the device
+            key = request_key(spec.sampling or GREEDY, self._rid)
         return Request(rid=self._rid, prompt=list(spec.prompt),
                        max_new_tokens=spec.max_new_tokens,
                        sampling=spec.sampling, eos_id=spec.eos_id,
                        encoder_input=encoder_input, tenant=spec.tenant,
                        priority_class=spec.priority_class,
                        deadline_ms=spec.deadline_ms,
-                       _key=request_key(spec.sampling or GREEDY, self._rid))
+                       _key=key)
 
     def submit(self, req: Union[Request, RequestSpec]):
         """Admission control: bounded queue + horizon check.
@@ -1404,11 +1441,12 @@ class Engine:
         hard caps are the per-sequence horizon and the request alone fitting
         the pool; transient exhaustion is handled later by eviction.
         """
-        if isinstance(req, RequestSpec):
-            mat = self._materialize(req)
-            self._submit(mat)
-            return mat
-        return self._submit(req)
+        with span("engine.submit"):
+            if isinstance(req, RequestSpec):
+                mat = self._materialize(req)
+                self._submit(mat)
+                return mat
+            return self._submit(req)
 
     def _submit(self, req: Request) -> bool:
         req.t_submit = time.perf_counter()
@@ -1466,6 +1504,7 @@ class Engine:
         self._slot_used[i] = True
         self._admit_counter += 1
         req._admit_seq = self._admit_counter
+        req.t_admitted = time.perf_counter()
         req.slot = i
         self.admitted += 1
         # fair scheduling charges the tenant's normalized service here, at
@@ -1482,7 +1521,8 @@ class Engine:
         self.presence_np[i] = s.presence_penalty
         self.frequency_np[i] = s.frequency_penalty
         # zeroed at (re)admission: eviction replay rebuilds the same counts
-        self.counts = self.counts.at[i].set(0)
+        with span("engine.dispatch"):
+            self.counts = self.counts.at[i].set(0)
         self._policy_dev = None
         self.trace.append({"event": "admit", "rid": req.rid, "slot": i,
                            "recycled": recycled})
@@ -1492,30 +1532,30 @@ class Engine:
                 self.telemetry.event("recycled", rid=req.rid, slot=i)
             if req.t_submit is not None:
                 self.telemetry.observe(
-                    "queue_delay_ms",
-                    (time.perf_counter() - req.t_submit) * 1e3)
+                    "queue_delay_ms", (req.t_admitted - req.t_submit) * 1e3)
 
     def _activate(self, req: Request, i: int, nxt0) -> None:
         """Prefill finished: first token is in hand, slot joins the decode
         batch (or the request completes outright for 1-token generations)."""
-        self.tokens = self.tokens.at[i, 0].set(nxt0[0])
         self.pos[i] = req.bucket
-        # reset the slot's finished bit — device-side, no sync; the first
-        # token may itself be the EOS
-        if req.eos_id is not None:
-            self.finished = self.finished.at[i].set(
-                jnp.equal(nxt0[0], req.eos_id))
-        else:
-            self.finished = self.finished.at[i].set(False)
-        if self._nan_guard:
-            # a recycled slot must not inherit its previous occupant's
-            # sticky finite-guard bit
-            self.poisoned = self.poisoned.at[i].set(False)
-        # the decode jit counts tokens[i, 0] for every row, so while this
-        # slot sat empty or mid-chunked-prefill the batch deposited phantom
-        # counts into it; zero here — not only at admission — so penalized
-        # replay streams stay bitwise the sequential ones
-        self.counts = self.counts.at[i].set(0)
+        with span("engine.dispatch"):
+            self.tokens = self.tokens.at[i, 0].set(nxt0[0])
+            # reset the slot's finished bit — device-side, no sync; the
+            # first token may itself be the EOS
+            if req.eos_id is not None:
+                self.finished = self.finished.at[i].set(
+                    jnp.equal(nxt0[0], req.eos_id))
+            else:
+                self.finished = self.finished.at[i].set(False)
+            if self._nan_guard:
+                # a recycled slot must not inherit its previous occupant's
+                # sticky finite-guard bit
+                self.poisoned = self.poisoned.at[i].set(False)
+            # the decode jit counts tokens[i, 0] for every row, so while
+            # this slot sat empty or mid-chunked-prefill the batch deposited
+            # phantom counts into it; zero here — not only at admission — so
+            # penalized replay streams stay bitwise the sequential ones
+            self.counts = self.counts.at[i].set(0)
         self.prefills += 1
         req.state = "active"
         req._first_tok = nxt0
@@ -1595,8 +1635,11 @@ class Engine:
                         raise
                     self._fault_unwind(req, i, "exception", str(e))
                     continue
-                self.cache = self._insert(self.cache, one, i)
+                with span("engine.dispatch"):
+                    self.cache = self._insert(self.cache, one, i)
                 self._activate(req, i, nxt0)
+        if self.queue:
+            self.admit_wait_slots += 1     # every slot holds a request
 
     def _growth_reserve(self) -> int:
         """Admission headroom: one free page per running sequence, so normal
@@ -1640,6 +1683,7 @@ class Engine:
                 # the head and the freed slot is retried immediately)
                 if self._maybe_preempt(req):
                     continue
+                self.admit_wait_slots += 1
                 return
             # prefix caching: find the longest cached chain of the padded
             # prompt's pages and take references on the hits immediately —
@@ -1656,6 +1700,7 @@ class Engine:
                 # pages; anyone else waits for pages to free up
                 if self._maybe_preempt(req):
                     continue
+                self.admit_wait_pages += 1
                 return
             pages = hits + self.allocator.alloc(need)
             del self.queue[idx]
@@ -1683,10 +1728,12 @@ class Engine:
                     # logits stand in for the skipped forward pass — zero
                     # prefill compute
                     s = req.sampling or GREEDY
-                    nxt0 = self._hit_sample(
-                        tail_logits, jnp.asarray(req._key),
-                        jnp.int32(req.bucket - 1), jnp.float32(s.temperature),
-                        jnp.int32(s.top_k), jnp.float32(s.top_p))
+                    with span("engine.dispatch"):
+                        nxt0 = self._hit_sample(
+                            tail_logits, jnp.asarray(req._key),
+                            jnp.int32(req.bucket - 1),
+                            jnp.float32(s.temperature), jnp.int32(s.top_k),
+                            jnp.float32(s.top_p))
                     self.prefix_full_hits += 1
                     self._activate(req, i, nxt0)
                 # prompts longer than one chunk prefill incrementally; at or
@@ -1720,15 +1767,17 @@ class Engine:
                         ppages = self.prefill_allocator.alloc(len(pages))
                         assert ppages is not None, \
                             "prefill pool is sized to fit every admission"
-                        self.prefill_pool = self._page_insert(
-                            self.prefill_pool, one["k"], one["v"],
-                            jnp.asarray(ppages, jnp.int32))
+                        with span("engine.dispatch"):
+                            self.prefill_pool = self._page_insert(
+                                self.prefill_pool, one["k"], one["v"],
+                                jnp.asarray(ppages, jnp.int32))
                         self._prefill_slot_pages[i] = ppages
                         self._kv_handoff(req, i)
                     else:
-                        self.pool = self._page_insert(
-                            self.pool, one["k"], one["v"],
-                            jnp.asarray(pages, jnp.int32))
+                        with span("engine.dispatch"):
+                            self.pool = self._page_insert(
+                                self.pool, one["k"], one["v"],
+                                jnp.asarray(pages, jnp.int32))
                     self._register_prefix(req, i, logits)
                     self._activate(req, i, nxt0)
             except Exception as e:   # noqa: BLE001 — FT quarantine
@@ -1789,9 +1838,10 @@ class Engine:
             if dev is None:
                 break          # device pool full: chain ends here
             k_np, v_np = self.host_pool.load(hid)
-            self.pool = self._page_upload(self.pool, jnp.asarray(k_np),
-                                          jnp.asarray(v_np),
-                                          jnp.int32(dev[0]))
+            with span("engine.dispatch"):
+                self.pool = self._page_upload(self.pool, jnp.asarray(k_np),
+                                              jnp.asarray(v_np),
+                                              jnp.int32(dev[0]))
             # the index keeps the single allocator ref, exactly as at
             # registration; the host copy dies with its last reference
             self.prefix_index.commit_page_in(k, dev[0])
@@ -1831,11 +1881,12 @@ class Engine:
         ids = self._slot_pages[i][n_hit:]
         toks = self._padded_prompt(req)[offset:]
         s = req.sampling or GREEDY
-        nxt, logits, self.pool = fn(
-            self.params, self.pool, snapshot(row),
-            jnp.asarray(toks)[None, :], jnp.asarray(ids, jnp.int32),
-            jnp.asarray(req._key), jnp.float32(s.temperature),
-            jnp.int32(s.top_k), jnp.float32(s.top_p))
+        with span("engine.prefill"), span("engine.dispatch"):
+            nxt, logits, self.pool = fn(
+                self.params, self.pool, snapshot(row),
+                jnp.asarray(toks)[None, :], jnp.asarray(ids, jnp.int32),
+                jnp.asarray(req._key), jnp.float32(s.temperature),
+                jnp.int32(s.top_k), jnp.float32(s.top_p))
         self._register_prefix(req, i, logits)
         return nxt
 
@@ -1862,8 +1913,9 @@ class Engine:
                 if hid is not None:
                     # exact device->host copy of the page bytes: spill is
                     # movement, never recompute
-                    k_np = np.asarray(self.pool["k_pages"][:, page])
-                    v_np = np.asarray(self.pool["v_pages"][:, page])
+                    with span("engine.readback"):
+                        k_np = np.asarray(self.pool["k_pages"][:, page])
+                        v_np = np.asarray(self.pool["v_pages"][:, page])
                     self.host_pool.store(hid[0], k_np, v_np)
                     self.prefix_index.insert_host(key, hid[0],
                                                   entry.get("logits"))
@@ -1891,9 +1943,10 @@ class Engine:
         release the prefill worker's pages."""
         ppages = self._prefill_slot_pages[i]
         pages = self._slot_pages[i][:len(ppages)]
-        self.pool = self._kv_transfer(
-            self.pool, self.prefill_pool,
-            jnp.asarray(ppages, jnp.int32), jnp.asarray(pages, jnp.int32))
+        with span("engine.dispatch"):
+            self.pool = self._kv_transfer(
+                self.pool, self.prefill_pool,
+                jnp.asarray(ppages, jnp.int32), jnp.asarray(pages, jnp.int32))
         self.prefill_allocator.free(ppages)
         self._prefill_slot_pages[i] = []
         self.prefill_table_np[i, :] = 0
@@ -1944,12 +1997,13 @@ class Engine:
             row = table[i][:width]
             t_c = time.perf_counter() if self.telemetry is not None else None
             pool_in = self.prefill_pool if self.disagg else self.pool
-            nxt, logits, pool_out = self._chunk_prefill(
-                self.params, pool_in, snapshot(row),
-                jnp.asarray(toks)[None, :], jnp.int32(off),
-                jnp.asarray(ids, jnp.int32), jnp.asarray(req._key),
-                jnp.float32(s.temperature), jnp.int32(s.top_k),
-                jnp.float32(s.top_p))
+            with span("engine.dispatch"):
+                nxt, logits, pool_out = self._chunk_prefill(
+                    self.params, pool_in, snapshot(row),
+                    jnp.asarray(toks)[None, :], jnp.int32(off),
+                    jnp.asarray(ids, jnp.int32), jnp.asarray(req._key),
+                    jnp.float32(s.temperature), jnp.int32(s.top_k),
+                    jnp.float32(s.top_p))
             if self.disagg:
                 self.prefill_pool = pool_out
             else:
@@ -2063,9 +2117,10 @@ class Engine:
                 page = self._alloc_one_pressured(i, req)
                 if page is None:
                     break              # slot evicted hunting for a copy
-                self.pool = self._page_copy(
-                    self.pool, jnp.asarray([row[j]], jnp.int32),
-                    jnp.asarray([page], jnp.int32))
+                with span("engine.dispatch"):
+                    self.pool = self._page_copy(
+                        self.pool, jnp.asarray([row[j]], jnp.int32),
+                        jnp.asarray([page], jnp.int32))
                 self.allocator.free([row[j]])
                 row[j] = page
                 self.page_table_np[i, j] = page
@@ -2137,10 +2192,13 @@ class Engine:
     def _device_page_table(self):
         """Decode sees real rows only for active slots; prefilling/free slots
         are masked to the null page so their scatters and gathers are inert."""
-        mask = np.fromiter((self.slots_req[i] is not None
-                            for i in range(self.ecfg.slots)), bool,
-                           self.ecfg.slots)
-        return jnp.asarray(np.where(mask[:, None], self.page_table_np, 0))
+        with span("engine.pages"):
+            mask = np.fromiter((self.slots_req[i] is not None
+                                for i in range(self.ecfg.slots)), bool,
+                               self.ecfg.slots)
+            table = np.where(mask[:, None], self.page_table_np, 0)
+            with span("engine.dispatch"):
+                return jnp.asarray(table)
 
     # -------------------------------------------------------------- stepping
 
@@ -2205,7 +2263,8 @@ class Engine:
                   and self.slots_req[i].eos_id is not None]
         if not polled:
             return
-        fin = np.asarray(self.finished)
+        with span("engine.readback"):
+            fin = np.asarray(self.finished)
         for i in polled:
             if fin[i]:
                 self._finish(self.slots_req[i], reason="eos")
@@ -2267,7 +2326,8 @@ class Engine:
             self._poison_np[f.slot] = True
             self._poison_dev = None
         if self._poison_dev is None:
-            self._poison_dev = snapshot(self._poison_np)
+            with span("engine.dispatch"):
+                self._poison_dev = snapshot(self._poison_np)
         return self._poison_dev
 
     def _nan_poll(self, active) -> None:
@@ -2284,7 +2344,8 @@ class Engine:
                 self.slots_req[i] is not None
                 and self.slots_req[i]._remaining <= 0 for i in active):
             return
-        bad = np.asarray(self.poisoned)
+        with span("engine.readback"):
+            bad = np.asarray(self.poisoned)
         for i in active:
             req = self.slots_req[i]
             if req is not None and bad[i]:
@@ -2528,6 +2589,10 @@ class Engine:
         (``debug_checks``), fire armed faults from the ``FaultPlan``, poll
         the device-side finite guard on the EOS cadence, and time the whole
         iteration against the wall-clock watchdog."""
+        with span("engine.step"):
+            return self._step()
+
+    def _step(self) -> int:
         t_step = time.perf_counter() \
             if (self.ecfg.watchdog_ms or self.telemetry is not None) else None
         self._activated = []
@@ -2541,89 +2606,23 @@ class Engine:
         stall = self._fault_fires("stall")
         if stall is not None:
             time.sleep(stall.stall_s)
-        self._admit_into_free_slots()
+        with span("engine.admit"):
+            self._admit_into_free_slots()
         if self.paged:
-            self._prefill_tick()
-            # cold start / post-drain: nothing to decode yet, so spend the
-            # step activating the shortest prompt instead of idling
-            while self._prefilling and \
-                    not any(r is not None for r in self.slots_req):
+            with span("engine.prefill"):
                 self._prefill_tick()
-            self._ensure_pages()
+                # cold start / post-drain: nothing to decode yet, so spend
+                # the step activating the shortest prompt instead of idling
+                while self._prefilling and \
+                        not any(r is not None for r in self.slots_req):
+                    self._prefill_tick()
+            with span("engine.pages"):
+                self._ensure_pages()
         active = [i for i in range(self.ecfg.slots)
                   if self.slots_req[i] is not None]
         if active:
-            # per-slot policy only changes at admit/finish/evict: the device
-            # copy is rebuilt then, not per step — steady decode uploads
-            # nothing but the position vector
-            if self._policy_dev is None:
-                self._policy_dev = (
-                    snapshot(self.keys_np), snapshot(self.temps_np),
-                    snapshot(self.topks_np), snapshot(self.topps_np),
-                    snapshot(self.eos_np), snapshot(self.presence_np),
-                    snapshot(self.frequency_np))
-            if self._spec is not None and not self.degraded:
-                try:
-                    self._maybe_raise("verify")
-                    self._spec_step(active)
-                except Exception as e:   # noqa: BLE001 — FT quarantine
-                    if not self.ft:
-                        raise
-                    self._decode_fault(e)
-            else:
-                keys, temps, topks, topps, eos, presence, frequency = \
-                    self._policy_dev
-                poison = self._arm_poison(active)
-                try:
-                    self._maybe_raise("decode")
-                    if self.paged:
-                        out = self._decode(
-                            self.params, self.pool,
-                            self._device_page_table(), self.tokens,
-                            snapshot(self.pos), keys, temps, topks,
-                            topps, eos, self.finished, self.counts,
-                            presence, frequency, poison, self.poisoned)
-                    else:
-                        out = self._decode(
-                            self.params, self.cache, self.tokens,
-                            snapshot(self.pos), keys, temps, topks,
-                            topps, eos, self.finished, self.counts,
-                            presence, frequency, poison, self.poisoned)
-                except Exception as e:   # noqa: BLE001 — FT quarantine
-                    if not self.ft:
-                        raise
-                    # the injection raises *before* the jit dispatch, so no
-                    # donated buffer was consumed: state is intact
-                    self._decode_fault(e)
-                else:
-                    nxt, self.finished, store, self.counts, self.poisoned = \
-                        out
-                    if self.paged:
-                        self.pool = store
-                    else:
-                        self.cache = store
-                    if self._spec is not None:
-                        self.degraded_steps += 1
-                    self.tokens = nxt[:, None]
-                    rids = tuple(self.slots_req[i].rid
-                                 if self.slots_req[i] is not None
-                                 else -1 for i in range(self.ecfg.slots))
-                    self._toklog.append((nxt, rids))
-                    self.decode_steps += 1
-                    self._step_emitted = len(active)
-                    self._occupancy_sum += len(active)
-                    for i in active:
-                        self.pos[i] += 1
-                        self.slots_req[i]._remaining -= 1
-                    # guard poll runs between the countdown and _finish: a
-                    # poisoned stream must be quarantined, never finalized
-                    self._nan_poll(active)
-                    for i in active:
-                        req = self.slots_req[i]
-                        if req is not None and req.state == "active" \
-                                and req._remaining <= 0:
-                            self._finish(req)
-                    self._eos_poll()
+            with span("engine.decode"):
+                self._decode_active(active)
         if self._sync_each_step:
             jax.block_until_ready(self.tokens)
         if self._activated and not self._sync_each_step:
@@ -2661,6 +2660,79 @@ class Engine:
                             req._itl_ms.append(itl)
         return len(active)
 
+    def _decode_active(self, active) -> None:
+        """One decode step for the ``active`` slots: upload the slots'
+        policy when it changed, dispatch the compiled step (or the
+        speculative iteration), then count down, finish and poll."""
+        # per-slot policy only changes at admit/finish/evict: the device
+        # copy is rebuilt then, not per step — steady decode uploads nothing
+        # but the position vector
+        if self._policy_dev is None:
+            with span("engine.dispatch"):
+                self._policy_dev = (
+                    snapshot(self.keys_np), snapshot(self.temps_np),
+                    snapshot(self.topks_np), snapshot(self.topps_np),
+                    snapshot(self.eos_np), snapshot(self.presence_np),
+                    snapshot(self.frequency_np))
+        if self._spec is not None and not self.degraded:
+            try:
+                self._maybe_raise("verify")
+                self._spec_step(active)
+            except Exception as e:   # noqa: BLE001 — FT quarantine
+                if not self.ft:
+                    raise
+                self._decode_fault(e)
+        else:
+            keys, temps, topks, topps, eos, presence, frequency = \
+                self._policy_dev
+            poison = self._arm_poison(active)
+            try:
+                self._maybe_raise("decode")
+                kv = (self.pool, self._device_page_table()) if self.paged \
+                    else (self.cache,)
+                with span("engine.dispatch"):
+                    out = self._decode(
+                        self.params, *kv, self.tokens, snapshot(self.pos),
+                        keys, temps, topks, topps, eos, self.finished,
+                        self.counts, presence, frequency, poison,
+                        self.poisoned)
+            except Exception as e:   # noqa: BLE001 — FT quarantine
+                if not self.ft:
+                    raise
+                # the injection raises *before* the jit dispatch, so no
+                # donated buffer was consumed: state is intact
+                self._decode_fault(e)
+            else:
+                nxt, self.finished, store, self.counts, self.poisoned = \
+                    out
+                if self.paged:
+                    self.pool = store
+                else:
+                    self.cache = store
+                if self._spec is not None:
+                    self.degraded_steps += 1
+                with span("engine.dispatch"):
+                    self.tokens = nxt[:, None]
+                rids = tuple(self.slots_req[i].rid
+                             if self.slots_req[i] is not None
+                             else -1 for i in range(self.ecfg.slots))
+                self._toklog.append((nxt, rids))
+                self.decode_steps += 1
+                self._step_emitted = len(active)
+                self._occupancy_sum += len(active)
+                for i in active:
+                    self.pos[i] += 1
+                    self.slots_req[i]._remaining -= 1
+                # guard poll runs between the countdown and _finish: a
+                # poisoned stream must be quarantined, never finalized
+                self._nan_poll(active)
+                for i in active:
+                    req = self.slots_req[i]
+                    if req is not None and req.state == "active" \
+                            and req._remaining <= 0:
+                        self._finish(req)
+                self._eos_poll()
+
     def _spec_step(self, active) -> None:
         """One draft/verify iteration: the fused jit proposes ``k`` tokens
         per slot, verifies all ``k+1`` positions in one batched call, and
@@ -2671,18 +2743,22 @@ class Engine:
         so only accepted tokens stay committed."""
         keys, temps, topks, topps, _eos, _presence, _frequency = \
             self._policy_dev
-        pos_dev = snapshot(self.pos)
-        if self.paged:
-            out, n_acc, self.pool, self._spec.cache = self._spec._step(
-                self.params, self._spec.params, self.pool,
-                self._device_page_table(), self._spec.cache, self.tokens,
-                pos_dev, keys, temps, topks, topps)
-        else:
-            out, n_acc, self.cache, self._spec.cache = self._spec._step(
-                self.params, self._spec.params, self.cache, self._spec.cache,
-                self.tokens, pos_dev, keys, temps, topks, topps)
-        out_np = np.asarray(out)
-        n_np = np.asarray(n_acc)
+        table = self._device_page_table() if self.paged else None
+        with span("engine.dispatch"):
+            pos_dev = snapshot(self.pos)
+            if self.paged:
+                out, n_acc, self.pool, self._spec.cache = self._spec._step(
+                    self.params, self._spec.params, self.pool, table,
+                    self._spec.cache, self.tokens, pos_dev, keys, temps,
+                    topks, topps)
+            else:
+                out, n_acc, self.cache, self._spec.cache = self._spec._step(
+                    self.params, self._spec.params, self.cache,
+                    self._spec.cache, self.tokens, pos_dev, keys, temps,
+                    topks, topps)
+        with span("engine.readback"):
+            out_np = np.asarray(out)
+            n_np = np.asarray(n_acc)
         toks_np = np.array(self.tokens)   # mutable host copy
         self.decode_steps += 1
         self.spec_steps += 1
@@ -2707,7 +2783,8 @@ class Engine:
                 self._finish(req)
             elif self.paged:
                 self._rollback_pages(i)
-        self.tokens = jnp.asarray(toks_np)
+        with span("engine.dispatch"):
+            self.tokens = jnp.asarray(toks_np)
 
     def run(self, requests: Sequence[Union[Request, RequestSpec]] = (), *,
             max_steps: int = 1_000_000,
@@ -2756,7 +2833,8 @@ class Engine:
         Done once, after the decode loop — the hot loop never syncs to host."""
         if not self._toklog:
             return
-        toks = np.asarray(jnp.stack([t for t, _ in self._toklog]))
+        with span("engine.readback"):
+            toks = np.asarray(jnp.stack([t for t, _ in self._toklog]))
         for srow, rids in zip(toks, (r for _, r in self._toklog)):
             for slot, rid in enumerate(rids):
                 if rid >= 0:
@@ -3024,6 +3102,8 @@ class Engine:
         self.kv_transfer_pages = 0
         self.rejected_queue_full = 0
         self.shed_deadline = 0
+        self.admit_wait_slots = 0
+        self.admit_wait_pages = 0
         self.faults_injected = 0
         self.quarantines = 0
         self.recovered = 0
@@ -3086,6 +3166,7 @@ class Engine:
                               self.slo_by_class.items())},
             rejected_queue_full=self.rejected_queue_full,
             shed_deadline=self.shed_deadline,
+            admit_wait_slots=self.admit_wait_slots,
             plan_cache=self.plan_cache.stats(),
         )
         if self.paged:
@@ -3095,6 +3176,7 @@ class Engine:
             out.peak_pages = self.peak_pages
             out.evictions = self.evictions
             out.prefill_chunks = self.prefill_chunks
+            out.admit_wait_pages = self.admit_wait_pages
         if self.prefix_cache:
             out.prefix_hits = self.prefix_hits
             out.prefix_full_hits = self.prefix_full_hits

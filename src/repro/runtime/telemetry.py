@@ -13,13 +13,20 @@ missing per-request observability as three host-side pieces behind one
   with monotonic host timestamps
   (``time.perf_counter``) into a bounded ring buffer — steady-state memory is
   O(``max_events``), and overflow is counted, never raised.
-* **Metric registry** — fixed-bucket latency histograms (TTFT, inter-token
-  latency, queue delay, prefill-chunk time, step wall time) plus counters and
-  gauges, summarized as p50/p95/p99 in ``EngineStats.telemetry`` and
-  exportable as Prometheus text exposition (:meth:`Telemetry.to_prometheus_text`).
+* **Metric registry** — fixed-bucket histograms (queue delay, and the host
+  dispatch times of the first token, of each token, of a prefill chunk and of
+  a step: ``HISTOGRAM_HELP``) plus counters and gauges, summarized as
+  p50/p95/p99 in ``EngineStats.telemetry`` and exportable as Prometheus
+  text exposition (:meth:`Telemetry.to_prometheus_text`).
 * **Trace export** — :meth:`Telemetry.to_chrome_trace` renders the event ring
   as Chrome ``trace_event`` JSON (one track per decode slot plus queue /
   allocator / scheduler tracks), viewable in Perfetto or ``chrome://tracing``.
+
+Separately, :func:`span` marks the engine's own work (``SPAN_NAMES``) on the
+``jax.profiler`` clock. Spans are always on and cost well under a
+microsecond each while no profile is being taken; a profile shows them in
+the same trace as the device ops they launch, which the event ring, on its
+own ``perf_counter`` clock, cannot.
 
 The contract that makes this safe to leave on in production: **no device
 syncs**. Every emission is a host timestamp + a deque append; device-side
@@ -31,7 +38,8 @@ via the ``mm(traced)`` annotation + ``upir.trace_emit`` op that
 ``core.plans.build_program(traced=True)`` renders into the program text.
 
 Timing caveat: the hot loop is asynchronous — decode steps are *dispatched*,
-not awaited — so step/ITL histograms measure host dispatch cadence. Under
+not awaited — so the TTFT, ITL, prefill-chunk and step histograms measure
+host dispatch, not when a token exists on the device or reaches a user. Under
 ``sync_per_step`` decode (and at natural sync points like EOS polls and run
 end) dispatch cadence converges to device latency; either way the numbers
 are deterministic in *count* and comparable run-to-run.
@@ -44,6 +52,8 @@ from collections import deque
 from dataclasses import dataclass
 from typing import Any, Deque, Dict, List, Optional, Tuple
 
+from jax.profiler import TraceAnnotation
+
 # Fixed histogram bucket upper bounds, in milliseconds. Fixed (rather than
 # adaptive) buckets keep observation O(1), make two runs' summaries directly
 # comparable, and render into Prometheus ``le`` labels unchanged.
@@ -53,13 +63,44 @@ LATENCY_BUCKETS_MS: Tuple[float, ...] = (
 
 # The histogram registry is fixed at construction — every engine exposes the
 # same metric names, populated or not, so dashboards never chase keys.
-HISTOGRAM_NAMES: Tuple[str, ...] = (
-    "ttft_ms",            # submit -> first emitted token
-    "itl_ms",             # per-token decode cadence (step time / tokens)
-    "queue_delay_ms",     # submit -> slot admission
-    "prefill_chunk_ms",   # host dispatch time of one prefill chunk
-    "step_ms",            # wall time of one engine step
+# Only ``queue_delay_ms`` is a latency a user feels. The others time host
+# dispatch: the hot loop does not wait for the device, so they read how fast
+# the host issues work, not when a token exists (``sync_per_step`` aside).
+HISTOGRAM_HELP: Dict[str, str] = {
+    "ttft_ms": "submit to the step that dispatched the first token "
+               "(host dispatch, not token delivery)",
+    "itl_ms": "host wall time of a decode step per emitted token "
+              "(dispatch cadence, not token delivery)",
+    "queue_delay_ms": "submit to slot admission",
+    "prefill_chunk_ms": "host time to dispatch one prefill chunk "
+                        "(not its device time)",
+    "step_ms": "host wall time of one engine step (dispatch, not device "
+               "time)",
+}
+HISTOGRAM_NAMES: Tuple[str, ...] = tuple(HISTOGRAM_HELP)
+
+# Host spans the engine opens on the profiler's clock (``span``), so a
+# ``jax.profiler`` trace shows them beside the device ops they launch. The
+# two leaf kinds mark where the host can block: everything else a step does
+# is its own Python work. Emission sites after each name.
+SPAN_NAMES: Tuple[str, ...] = (
+    "engine.step",      # Engine.step: one whole iteration
+    "engine.submit",    # Engine.submit: validation, PRNG key, enqueue
+    "engine.admit",     # Engine._admit_into_free_slots / _admit_paged
+    "engine.prefill",   # _prefill_tick, one-shot and suffix prefill
+    "engine.pages",     # _ensure_pages, _device_page_table
+    "engine.decode",    # Engine.step's decode block, finishes and polls
+    "engine.dispatch",  # a call into a compiled program, uploads included
+    "engine.readback",  # a device-to-host read
 )
+
+
+def span(name: str) -> TraceAnnotation:
+    """A host span named from ``SPAN_NAMES``, recorded when a ``jax.profiler``
+    trace is running and near free otherwise. It takes no arguments: they
+    would cost time on every call and be encoded into the event's name."""
+    return TraceAnnotation(name)
+
 
 # Lifecycle event vocabulary (documented; emission sites in parentheses).
 EVENT_NAMES: Tuple[str, ...] = (
@@ -260,6 +301,10 @@ class Telemetry:
         hists += [(h.name, h) for _, h in sorted(self.ttft_by_class.items())]
         for name, h in hists:
             metric = f"repro_engine_{name}"
+            help_ = HISTOGRAM_HELP.get(
+                name, "submit to the step that dispatched the first token, "
+                      "one priority class (host dispatch)")
+            lines.append(f"# HELP {metric} {help_}, milliseconds")
             lines.append(f"# TYPE {metric} histogram")
             cum = 0
             for bound, c in zip(h.bounds, h.counts):
