@@ -9,7 +9,8 @@ algorithm for real TPUs; both are validated against ``kernels/ref.py``.
 from __future__ import annotations
 
 import functools
-from typing import Optional, Tuple
+import math
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -304,13 +305,17 @@ def gather_kv_pages(pool, page_table):
 
 def attention_decode_paged(q, k_pages, v_pages, page_table, pos, *,
                            window: int = 0, new_kv=None):
-    """One-token attention against a paged KV pool.
+    """One-token attention against a paged KV pool, over whole rows.
 
     q: [B,1,H,hd]; k_pages/v_pages: [NP,PS,KV,hd]; page_table: [B,P] int32;
     pos: [B]. Same contract as ``attention_decode`` (including deferred-insert
     ``new_kv``) but the cache is gathered through the page table, and the
     layout is logical-order (non-rolling), so a ``window`` masks positions
     ``[limit - window, limit)`` instead of rolling slots.
+
+    It gathers all ``P`` pages of every row, whatever the row's position.
+    The paged decode step reads only the live blocks instead
+    (``attention_decode_live``); this whole-row form is its reference.
     """
     k_c = gather_kv_pages(k_pages, page_table)
     v_c = gather_kv_pages(v_pages, page_table)
@@ -321,6 +326,147 @@ def attention_decode_paged(q, k_pages, v_pages, page_table, pos, *,
     if window:
         valid &= slot >= (limit - window)[:, None]
     return _attend_cached(q, k_c, v_c, valid, new_kv)
+
+
+# A live block is ``block_pages`` consecutive logical pages of one row, about
+# this many tokens. Paged decode gathers the blocks that hold a row's cached
+# positions, not the row's whole horizon.
+LIVE_BLOCK_TOKENS = 128
+# Most widths of the paged decode step's live-block ladder.
+DECODE_WIDTHS = 8
+
+
+def block_pages(page_size: int, pages_per_row: int) -> int:
+    """Pages per live block: ``LIVE_BLOCK_TOKENS`` worth, at least one page
+    and at most a whole row."""
+    return max(1, min(LIVE_BLOCK_TOKENS // page_size, pages_per_row))
+
+
+def row_blocks(pos, block_tokens: int, blocks_per_row: int):
+    """Live blocks of a decoding row at position ``pos`` (numpy or jax
+    array): every block holding a position ``<= pos``. The engine counts
+    the step's blocks with the same function the step's block list uses."""
+    return (pos // block_tokens + 1).clip(max=blocks_per_row)
+
+
+def decode_block_ladder(slots: int, blocks_per_row: int) -> Tuple[int, ...]:
+    """The widths, in live blocks, of the paged decode attention: geometric
+    from one block per slot up to every block of every slot (so any state
+    fits), at most ``DECODE_WIDTHS`` of them. A horizon of one block per row
+    gives one width."""
+    top = slots * blocks_per_row
+    ratio = blocks_per_row ** (1 / (DECODE_WIDTHS - 1))
+    return tuple(sorted({min(top, math.ceil(slots * ratio ** k))
+                         for k in range(DECODE_WIDTHS)}))
+
+
+class LiveBlocks(NamedTuple):
+    """The live KV blocks of a decode batch, ordered by row, then block.
+
+    row [N] int32: owning row, ``B`` on padding entries (past the live
+    count), which the per-row merge drops; block [N] int32: the block's
+    index within its row; pages [N, c] int32: its physical pages
+    (``NULL_PAGE`` on padding); count [] int32: the live entries."""
+
+    row: jax.Array
+    block: jax.Array
+    pages: jax.Array
+    count: jax.Array
+
+    def head(self, n: int) -> "LiveBlocks":
+        """The first ``n`` entries: the list at width ``n`` (which holds
+        every live entry when ``n >= count``)."""
+        return LiveBlocks(self.row[:n], self.block[:n], self.pages[:n],
+                          self.count)
+
+
+def live_block_list(page_table, pos, *, n_blocks: int, block_pages: int,
+                    page_size: int) -> LiveBlocks:
+    """Build the ``n_blocks``-entry live-block list of a decode batch.
+
+    page_table: [B,P] int32 (null rows for inactive slots); pos: [B]. A row
+    with a mapped first page holds ``row_blocks(pos)`` blocks; a null row
+    holds none. Entries past ``n_blocks`` are dropped, so callers pick
+    ``n_blocks`` at least the batch's total.
+    """
+    B, P = page_table.shape
+    c = block_pages
+    nb = -(-P // c)
+    table = jnp.pad(page_table, ((0, 0), (0, nb * c - P)),
+                    constant_values=NULL_PAGE).reshape(B, nb, c)
+    per_row = jnp.where(page_table[:, 0] != NULL_PAGE,
+                        row_blocks(pos, c * page_size, nb), 0)
+    ends = jnp.cumsum(per_row)                               # [B]
+    j = jnp.arange(n_blocks, dtype=ends.dtype)
+    row = (ends[None, :] <= j[:, None]).sum(axis=1, dtype=jnp.int32)
+    r = jnp.minimum(row, B - 1)
+    live = row < B
+    block = jnp.where(live, j - (ends[r] - per_row[r]), 0).astype(jnp.int32)
+    pages = jnp.where(live[:, None], table[r, block], NULL_PAGE)
+    return LiveBlocks(row, block, pages, ends[-1].astype(jnp.int32))
+
+
+def attention_decode_live(q, k_pages, v_pages, blocks: LiveBlocks, pos,
+                          new_kv, widths: Tuple[int, ...], *, window: int = 0):
+    """``attention_decode_blocks`` at the smallest of ``widths`` that holds
+    ``blocks.count``, chosen on the device: each width is one branch of a
+    ``lax.switch`` over the first ``width`` entries of ``blocks`` (built at
+    ``widths[-1]``), so one compiled program serves every width."""
+    def at(width):
+        return lambda q, k_p, v_p, bl, pos, new_kv: attention_decode_blocks(
+            q, k_p, v_p, bl.head(width), pos, new_kv, window=window)
+
+    branch = jnp.searchsorted(jnp.asarray(widths, jnp.int32), blocks.count)
+    return jax.lax.switch(branch, [at(w) for w in widths],
+                          q, k_pages, v_pages, blocks, pos, new_kv)
+
+
+def attention_decode_blocks(q, k_pages, v_pages, blocks: LiveBlocks, pos,
+                            new_kv, *, window: int = 0):
+    """One-token attention against a paged KV pool, over live blocks only.
+
+    The contract of ``attention_decode_paged`` with the new token's K/V
+    deferred (``new_kv``, as the decode step always passes it), but K/V is
+    gathered per live block ([N, c*PS, KV, hd]) instead of per whole row.
+    Each block is scored against its row's query (GQA-native) and masked by
+    position as the whole-row form masks; the row's softmax maximum is the
+    segment maximum over its blocks and the new token, so every block's
+    probabilities, sums and value products are those of the whole-row form,
+    summed per row in f32.
+    """
+    B, _, H, hd = q.shape
+    _, PS, KV, _ = k_pages.shape
+    G = H // KV
+    N, c = blocks.pages.shape
+    T = c * PS
+    k_b = k_pages[blocks.pages].reshape(N, T, KV, hd)
+    v_b = v_pages[blocks.pages].reshape(N, T, KV, hd)
+    r = jnp.minimum(blocks.row, B - 1)
+    lim = jnp.where(blocks.row < B, pos[r], 0)                # [N]
+    tpos = blocks.block[:, None] * T + jnp.arange(T)[None, :]  # [N,T]
+    valid = tpos < lim[:, None]
+    if window:
+        valid &= tpos >= (lim - window)[:, None]
+    qg = q.reshape(B, KV, G, hd)
+    scores = jnp.einsum("nkgh,ntkh->nkgt", qg[r],
+                        k_b).astype(jnp.float32)
+    scores = scores / np.sqrt(hd)
+    scores = jnp.where(valid[:, None, None], scores, -1e30)
+    segment = dict(segment_ids=blocks.row, num_segments=B,
+                   indices_are_sorted=True)
+    k_new, v_new = new_kv
+    s_new = jnp.einsum("bkgh,bskh->bkgs", qg,
+                       k_new).astype(jnp.float32)[..., 0] / np.sqrt(hd)
+    m = jnp.maximum(jax.ops.segment_max(scores.max(axis=-1), **segment),
+                    s_new)                                    # [B,KV,G]
+    p = jnp.exp(scores - m[r][..., None])                     # [N,KV,G,T]
+    l_c = jax.ops.segment_sum(p.sum(axis=-1), **segment)      # [B,KV,G]
+    o_b = jnp.einsum("nkgt,ntkh->nkgh", p.astype(q.dtype), v_b)
+    o_c = jax.ops.segment_sum(o_b.astype(jnp.float32), **segment)
+    p_n = jnp.exp(s_new - m)                                  # [B,KV,G]
+    o = o_c + p_n[..., None] * v_new[:, 0, :, None, :].astype(jnp.float32)
+    out = (o / (l_c + p_n)[..., None]).astype(q.dtype)
+    return out.reshape(B, 1, H, hd)
 
 
 def cache_insert_chunk(cache, new, pos):

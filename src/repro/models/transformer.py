@@ -28,10 +28,12 @@ from ..core.act_sharding import (anchor_block_grads, constrain,
                                  fsdp_gather_block)
 from . import mamba2, moe as moe_lib, xlstm as xlstm_lib
 from .layers import (apply_rope, attention_chunked, attention_decode,
-                     attention_decode_paged, attention_full,
-                     attention_prefill_chunk, cache_insert, cache_insert_chunk,
-                     cache_insert_paged, cache_insert_paged_chunk,
-                     embed_lookup, gather_kv_pages, mlp_apply, norm)
+                     attention_decode_live, attention_full,
+                     attention_prefill_chunk, block_pages, cache_insert,
+                     cache_insert_chunk, cache_insert_paged,
+                     cache_insert_paged_chunk, decode_block_ladder,
+                     embed_lookup, gather_kv_pages, live_block_list,
+                     mlp_apply, norm)
 
 CHUNKED_ATTN_THRESHOLD = 8192
 
@@ -626,12 +628,17 @@ def decode_step_paged(cfg: ArchConfig, params, pool, page_table, tokens, pos,
     inactive slots). Returns (logits [B,1,V], pool).
 
     Structure mirrors the dense ``decode_step``: the pool is scanned
-    READ-ONLY per layer, attention gathers K/V through the page table
-    (``kernel.attn_impl='pallas'`` streams physical pages in the Pallas
-    kernel instead), and the new token's K/V is scattered into its page
-    once, post-scan. The gather and attention, the MLP and the scatter run
-    under the named scopes ``attention``, ``mlp`` and ``kv_write``, so a
-    device profile can attribute the step's time to them.
+    READ-ONLY per layer, attention gathers K/V through the page table, and
+    the new token's K/V is scattered into its page once, post-scan. The XLA
+    attention gathers only the batch's live blocks of ``block_pages`` pages
+    (128 tokens): the step builds the list of (row, block, pages) from
+    ``page_table`` and ``pos`` once (``live_block_list``), and every layer
+    gathers the smallest width of ``decode_block_ladder`` that holds it
+    (``attention_decode_live``), not all ``P`` pages of every row.
+    ``kernel.attn_impl='pallas'`` streams the physical pages of whole rows
+    in the Pallas kernel instead. The gather and attention, the MLP and the
+    scatter run under the named scopes ``attention``, ``mlp`` and
+    ``kv_write``, so a device profile can attribute the step's time to them.
     """
     _check_paged(cfg)
     kernel = kernel or KernelSpec()
@@ -639,6 +646,12 @@ def decode_step_paged(cfg: ArchConfig, params, pool, page_table, tokens, pos,
     B = tokens.shape[0]
     x = constrain(embed_lookup(params["embed"], tokens, dtype), "hidden")
     H, KV, hd = cfg.n_heads, cfg.n_kv_heads, cfg.hd
+    if kernel.attn_impl == "xla":
+        ps, P = pool["k_pages"].shape[2], page_table.shape[1]
+        c = block_pages(ps, P)
+        widths = decode_block_ladder(B, -(-P // c))
+        blocks = live_block_list(page_table, pos, n_blocks=widths[-1],
+                                 block_pages=c, page_size=ps)
 
     def body(x, xs_l):
         p_l, k_pg, v_pg = xs_l
@@ -658,8 +671,8 @@ def decode_step_paged(cfg: ArchConfig, params, pool, page_table, tokens, pos,
                                            new_kv=(k, v),
                                            interpret=kernel.interpret)
             else:
-                o = attention_decode_paged(q, k_pg, v_pg, page_table, pos,
-                                           new_kv=(k, v))
+                o = attention_decode_live(q, k_pg, v_pg, blocks, pos, (k, v),
+                                          widths)
         a = jnp.einsum("bsh,hd->bsd", o.reshape(B, 1, H * hd).astype(dtype),
                        p_l["wo"].astype(dtype))
         x = x + a.astype(x.dtype)

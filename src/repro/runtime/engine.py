@@ -31,10 +31,12 @@ Two KV-cache layouts (``EngineConfig.kv_layout``):
     the newest-admitted sequence is evicted (pages freed, request requeued at
     the front; decode — greedy or sampled — is a deterministic function of
     the request's snapshotted PRNG key and position, so recomputation
-    reproduces the same stream). Decode gathers K/V through the page table —
-    host XLA gather
-    or the Pallas kernel (``kernels/paged_attention``) per
-    ``EngineConfig.decode_kernel``.
+    reproduces the same stream). Decode gathers K/V through the page table
+    per ``EngineConfig.decode_kernel``: the XLA path gathers only the live
+    blocks of 128 tokens (``models.layers.attention_decode_live``), at the
+    smallest width of a ladder (``decode_block_ladder``) that holds the
+    active slots' blocks; the Pallas kernel (``kernels/paged_attention``)
+    streams every page of every row.
 
 Paged mode also enables **chunked prefill** (``prefill_chunk > 0``): prompts
 prefill page-aligned chunk by chunk, one chunk per engine step, interleaved
@@ -103,6 +105,7 @@ entries that ``run_pipeline`` appends when the plan is first compiled.
 """
 from __future__ import annotations
 
+import bisect
 import copy
 import dataclasses
 import hashlib
@@ -119,7 +122,8 @@ from ..configs.base import ArchConfig, ShapeCfg
 from ..core.lower import PlanCache, default_plan_cache
 from ..models import api
 from ..models.api import KernelSpec
-from ..models.layers import cache_copy_pages, cache_write_pages
+from ..models.layers import (block_pages, cache_copy_pages, cache_write_pages,
+                             decode_block_ladder, row_blocks)
 from .faults import (EngineSnapshot, FailureInfo, FaultPlan, FaultSpec,
                      InjectedFault, note_failure, note_quarantine, note_retry)
 from .sampling import (GREEDY, SamplingParams, decode_select, poison_and_guard,
@@ -342,8 +346,9 @@ class EngineConfig:
       prefill this many page-aligned tokens per engine step, interleaved
       with decode (chunked prefill; cuts tail TTFT under long prompts).
     * ``decode_kernel`` / ``interpret`` — paged decode attention
-      implementation (``"xla"`` gather or the ``"pallas"`` paged-attention
-      kernel, compiled for the TPU unless ``interpret``); validated here
+      implementation (``"xla"`` gather of the live blocks, or the
+      ``"pallas"`` paged-attention kernel over whole rows, compiled for the
+      TPU unless ``interpret``); validated here
       once as a ``KernelSpec`` and keyed into the paged jit entries.
     * ``prefix_cache`` *[plan key]* — paged mode only: automatic prefix
       caching with ref-counted page sharing and copy-on-write (adds
@@ -428,7 +433,7 @@ class EngineConfig:
     page_size: int = 16                # tokens per physical KV page
     num_pages: int = 0                 # allocatable pages; 0 = slots*ceil(max_seq/page_size)
     prefill_chunk: int = 0             # 0 = one-shot prefill; else chunk length
-    decode_kernel: str = "xla"         # xla (gather) | pallas (paged-attention kernel)
+    decode_kernel: str = "xla"         # xla (live-block gather) | pallas (paged-attention kernel)
     interpret: bool = False            # Pallas interpreter (CPU-only runs)
     prefix_cache: bool = False         # paged only: share prompt-prefix pages
     # ---- tiered KV + disaggregated prefill/decode (runtime.tiered)
@@ -794,6 +799,13 @@ class EngineStats:
     # steps whose admission left the queue waiting because the pool held
     # fewer free pages than the head's prompt plus the growth reserve
     admit_wait_pages: Optional[int] = None
+    # live-block decode (XLA paged attention): blocks holding the active
+    # slots' cached tokens, and blocks the dispatched widths gathered, each
+    # summed over decode steps; their ratio is the share of gathered blocks
+    # that hold live tokens (1.0 when every dispatched block is live)
+    decode_live_blocks: Optional[int] = None
+    decode_dispatched_blocks: Optional[int] = None
+    decode_block_fill: Optional[float] = None
     # ---- prefix-cache section
     prefix_hits: Optional[int] = None
     prefix_full_hits: Optional[int] = None
@@ -1005,6 +1017,15 @@ class Engine:
             if self.paged else 0
         page_geom = (self.num_pages, ecfg.page_size, self.pages_per_slot) \
             if self.paged else None
+        # live-block decode: the XLA paged step gathers only the blocks that
+        # hold the active slots' tokens, at the smallest width of its ladder
+        # that fits them (the widths it counts in); the Pallas kernel reads
+        # whole rows and has no ladder
+        self._block_pages = block_pages(ecfg.page_size, self.pages_per_slot)
+        self._blocks_per_row = -(-self.pages_per_slot // self._block_pages)
+        self.decode_widths = decode_block_ladder(
+            ecfg.slots, self._blocks_per_row) \
+            if self.paged and self._kernel.attn_impl == "xla" else ()
         # host-tier capacity: 0 sizes it like the device pool
         self.host_pages = (ecfg.host_pages or self.num_pages) \
             if self.tiered else 0
@@ -1162,6 +1183,16 @@ class Engine:
         self.reset_stats()
 
     # ------------------------------------------------------------ step fns
+
+    def _live_blocks(self, active) -> Tuple[int, int]:
+        """Live KV blocks of the ``active`` slots, the count the decode
+        step's block list is built from, and the ladder width the step
+        gathers for it."""
+        live = int(row_blocks(self.pos[active],
+                              self._block_pages * self.ecfg.page_size,
+                              self._blocks_per_row).sum())
+        return live, self.decode_widths[
+            bisect.bisect_left(self.decode_widths, live)]
 
     def _build_decode(self):
         cfg = self.cfg
@@ -2711,6 +2742,10 @@ class Engine:
                     self.cache = store
                 if self._spec is not None:
                     self.degraded_steps += 1
+                if self.decode_widths:
+                    live, width = self._live_blocks(active)
+                    self.decode_live_blocks += live
+                    self.decode_dispatched_blocks += width
                 with span("engine.dispatch"):
                     self.tokens = nxt[:, None]
                 rids = tuple(self.slots_req[i].rid
@@ -3104,6 +3139,8 @@ class Engine:
         self.shed_deadline = 0
         self.admit_wait_slots = 0
         self.admit_wait_pages = 0
+        self.decode_live_blocks = 0
+        self.decode_dispatched_blocks = 0
         self.faults_injected = 0
         self.quarantines = 0
         self.recovered = 0
@@ -3177,6 +3214,12 @@ class Engine:
             out.evictions = self.evictions
             out.prefill_chunks = self.prefill_chunks
             out.admit_wait_pages = self.admit_wait_pages
+        if self.decode_widths:
+            out.decode_live_blocks = self.decode_live_blocks
+            out.decode_dispatched_blocks = self.decode_dispatched_blocks
+            out.decode_block_fill = (
+                self.decode_live_blocks / self.decode_dispatched_blocks
+                if self.decode_dispatched_blocks else 0.0)
         if self.prefix_cache:
             out.prefix_hits = self.prefix_hits
             out.prefix_full_hits = self.prefix_full_hits
